@@ -36,7 +36,10 @@ def main() -> None:
     import jax.numpy as jnp
 
     from repro.configs import get
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.models.model import Model
+
+    setup_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

@@ -36,9 +36,12 @@ def main() -> None:
     from repro.checkpoint import CheckpointStore
     from repro.configs import get
     from repro.data import make_train_iterator
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.models.model import Model
     from repro.optim import AdamW, CosineSchedule
     from repro.runtime import TrainSupervisor
+
+    setup_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

@@ -371,48 +371,13 @@ def moe_apply(params: dict, cfg: ModelConfig, x: Array) -> tuple[Array, Array]:
     return out.reshape(b, s, d), aux
 
 
-def _ambient_mesh():
-    """Version-compatible ambient-mesh lookup: ``jax.sharding
-    .get_abstract_mesh`` (newer JAX) or the thread-resources physical mesh
-    (older releases). Returns None when no mesh is in scope."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax.interpreters import pxla
-
-        return pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-
-
-def _shard_map(*args, **kwargs):
-    """``jax.shard_map`` where present; the experimental entry point
-    otherwise. Some releases spell ``check_vma`` as ``check_rep`` (including
-    a window where ``jax.shard_map`` itself still takes ``check_rep``), so
-    pick the spelling off the actual signature."""
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    if "check_vma" in kwargs:
-        try:
-            params = inspect.signature(fn).parameters
-        except (TypeError, ValueError):
-            params = {}
-        if "check_vma" not in params:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return fn(*args, **kwargs)
-
-
 def _moe_shard_map(params: dict, cfg: ModelConfig, x: Array):
     """Expert-parallel MoE via shard_map + all_to_all. Returns (None, 0) when
     no suitable mesh is ambient (single-device smoke paths)."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = _ambient_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return None, jnp.zeros((), jnp.float32)
     ep = mesh.shape["model"]
     if cfg.n_experts % ep != 0:
@@ -445,7 +410,7 @@ def _moe_shard_map(params: dict, cfg: ModelConfig, x: Array):
         "wg": P("model", None, None),
         "wo": P("model", None, None),
     }
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(param_specs, P(batch_axes, None, None)),

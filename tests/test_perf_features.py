@@ -67,7 +67,8 @@ def test_moe_shard_map_matches_gspmd():
     cfg0 = dataclasses.replace(
         get("phi3.5-moe-42b-a6.6b").reduced(), remat="none", capacity_factor=4.0
     )
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     batch = {
         "tokens": jax.random.randint(jax.random.key(1), (4, 16), 0, cfg0.vocab_size)
     }
