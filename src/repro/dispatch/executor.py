@@ -12,10 +12,10 @@
 
 * :class:`ScheduledExecutor` is the scheduler-backed path: concurrent
   staging *plus* a :class:`~repro.sched.state_cache.ConfigStateCache` in
-  front of the launch descriptors, so only fields whose values changed
-  since the previous launch are counted as host→device traffic — runtime
-  deduplication stacked on runtime overlap, the full `repro.sched` story
-  on the real JAX runtime.
+  front of the launch descriptors, which counts the fields whose values
+  changed since the previous launch apart from those that did not. The
+  count models runtime deduplication; the launch itself still passes, and
+  copies, every field.
 
 All report a timeline breakdown so benchmarks can place the measurement on
 the configuration roofline (host prep time ⇒ T_calc of Eq. 4).
@@ -29,6 +29,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -125,15 +126,20 @@ def _leaf_bytes(name, v) -> int:
 
 
 class ScheduledExecutor:
-    """Concurrent staging + runtime descriptor deduplication.
+    """Concurrent staging + an account of descriptor deduplication.
 
     Each launch descriptor (a pytree) flows through a
     :class:`~repro.sched.state_cache.ConfigStateCache`: fields bit-identical
-    to the previous launch are elided from the traffic accounting — they are
-    device-resident state, exactly like an unwritten configuration register
-    (§3.2/§5.4 at the runtime layer). The device still sees the full
-    argument tree; what the report splits out is how many descriptor bytes
-    actually needed to cross the boundary.
+    to the previous launch are counted as elided, as an unwritten
+    configuration register would be (§3.2/§5.4 at the runtime layer). Only
+    the count changes: ``device_fn`` still gets the full argument tree, and
+    whatever it copies to the device crosses the boundary on every launch.
+    What the report splits out is how many descriptor bytes would need to
+    cross it if unchanged fields stayed resident.
+
+    ``launch`` opens two ``jax.profiler`` spans: ``dispatch.config_cache``
+    around the cache pass and ``dispatch.ring_wait`` around a wait for the
+    oldest staged launch (``drain`` opens the latter too).
 
     Two entry points: the batch :meth:`run` loop (``host_prep`` builds each
     step's descriptor), and the incremental :meth:`launch` API that stateful
@@ -181,25 +187,30 @@ class ScheduledExecutor:
         # the cache comparison is host descriptor work: count it as prep
         # (T_calc), and compare host-side views so accounting never forces
         # a device sync mid-pipeline
-        leaves, _ = jax.tree_util.tree_flatten_with_path(args)
-        plan = self.cache.dispatch(
-            self.tenant,
-            {jax.tree_util.keystr(k): _host_view(v) for k, v in leaves},
-        )
+        with TraceAnnotation("dispatch.config_cache"):
+            leaves, _ = jax.tree_util.tree_flatten_with_path(args)
+            plan = self.cache.dispatch(
+                self.tenant,
+                {jax.tree_util.keystr(k): _host_view(v) for k, v in leaves},
+            )
         self._prep_s += time.perf_counter() - tp
         self._sent += plan.bytes_sent
         self._elided += plan.bytes_elided
         state = self.device_fn(state, args)  # async dispatch: returns early
         self._inflight.append(self.sync_fn(state))
         if len(self._inflight) > self.depth:
-            jax.block_until_ready(self._inflight.popleft())
+            with TraceAnnotation("dispatch.ring_wait"):
+                jax.block_until_ready(self._inflight.popleft())
         self._steps += 1
         return state
 
     def drain(self) -> None:
         """Retire every staged launch (end-of-run / engine idle barrier)."""
-        while self._inflight:
-            jax.block_until_ready(self._inflight.popleft())
+        if not self._inflight:
+            return
+        with TraceAnnotation("dispatch.ring_wait"):
+            while self._inflight:
+                jax.block_until_ready(self._inflight.popleft())
 
     def report(self, wall_s: float) -> ExecReport:
         """Cumulative traffic split over every launch so far."""

@@ -8,10 +8,11 @@ request's slot is handed to the next queued request immediately — no
 drain-the-batch bubbles.
 
 Configuration-wall connection: the per-launch descriptor is a few dozen
-bytes against a device-resident multi-GiB cache — the deduplicated-
-configuration serving design the paper's §5.4 implies: everything invariant
-lives on-device; only the changing fields cross the host→device boundary
-each step. Two designs narrow that boundary further:
+bytes against a device-resident multi-GiB cache, and the weights, the cache
+and (under fused sampling) the input tokens stay on the device. Each launch
+still copies every leaf it passes to the jitted step from host to device —
+the changing fields and the unchanged masks alike (``h2d``, below). Two
+designs narrow that boundary:
 
 * **Fused sampling** (``sampling="fused"``, the default): the decode launch
   runs the greedy-sampling epilogue on-device
@@ -21,9 +22,8 @@ each step. Two designs narrow that boundary further:
   Because the sampled ids stay device-resident and feed the next launch
   directly, the decode descriptor drops its ``tokens`` leaf entirely: the
   host injects tokens only through ``token_overrides``/``override_mask``
-  (admissions and freed slots), which elide in steady-state decode. The
-  steady-state descriptor is ``{positions}`` plus elided residents — the
-  narrowest the boundary gets. ``sampling="host"`` keeps the classic
+  (admissions and freed slots). The decode launch copies those two,
+  ``positions`` and ``live_mask``. ``sampling="host"`` keeps the classic
   logits-returning launch (the A/B baseline, bit-identical token streams).
 
 * **Batched prefill**: admission runs the prompt through
@@ -34,25 +34,31 @@ each step. Two designs narrow that boundary further:
   ``slot_mask``) is priced by the bridge like any other launch.
 
 Every launch goes through a :class:`~repro.dispatch.ScheduledExecutor`
-(``engine.executor``): descriptor elision drives the *real* launch path,
-not just accounting. The executor's
-:class:`~repro.sched.state_cache.ConfigStateCache` (aliased as
-``engine.config_cache``) splits each descriptor into sent vs.
-device-resident fields, and its depth-bounded staging ring keeps prefill
+(``engine.executor``), whose depth-bounded staging ring keeps prefill
 launches in flight while the host prepares the next one — the serving twin
-of OpenGeMM's staged configuration. ``engine.config_traffic()`` reports the
-split for roofline placement.
+of OpenGeMM's staged configuration. The executor's
+:class:`~repro.sched.state_cache.ConfigStateCache` (aliased as
+``engine.config_cache``) counts which descriptor fields changed since the
+last launch; ``engine.config_traffic()`` reports that split, which is an
+account and not the copy. The copies launches really make are counted in
+``engine.h2d``, by launch kind.
+
+Tracing: ``step()`` and the layers under it open ``jax.profiler`` spans
+(``serving.*`` here, ``dispatch.*`` in the executor) on the thread that runs
+the loop, so a profiler trace puts the host's work beside the device's on
+one clock. Their arguments are computed only while a profiler is tracing.
 """
 
 from __future__ import annotations
 
-import functools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.dispatch import ScheduledExecutor
 
@@ -64,6 +70,17 @@ class Request:
     max_new_tokens: int
     generated: list[int] = field(default_factory=list)
     done: bool = False
+    submitted_at: float = 0.0  # time.perf_counter() at submit()
+
+
+@dataclass
+class H2DCount:
+    """Host→device copies that launches of one kind made: the numpy leaves
+    ``_device_fn`` passes through ``jnp.asarray``."""
+
+    launches: int = 0
+    copies: int = 0
+    bytes: int = 0
 
 
 class ServingEngine:
@@ -89,7 +106,8 @@ class ServingEngine:
         self.tokens = np.zeros((max_slots, 1), np.int32)
         # fused sampling: host→device token injections for the next decode
         # launch (admitted prompts' last token; zero for freed slots) —
-        # all-False mask in steady state, so both leaves elide
+        # all-False mask in steady state; both leaves are copied every
+        # decode launch all the same
         self._overrides = np.zeros((max_slots,), np.int32)
         self._override_mask = np.zeros((max_slots,), bool)
         if sampling == "fused":
@@ -99,6 +117,9 @@ class ServingEngine:
         self.slot_req: list[Request | None] = [None] * max_slots
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
+        self.steps = 0
+        self.h2d = {"prefill": H2DCount(), "decode": H2DCount()}
+        self._h2d_last = (0, 0)  # the latest launch's (copies, bytes)
         # decode_fn/prefill_fn let N engines of one model share a single
         # compiled step (the bridge runs many tenant engines of the same
         # architecture; each call still passes its own donated cache). A
@@ -114,8 +135,8 @@ class ServingEngine:
         self.on_launch = on_launch
         # scheduled launch path: the executor owns the staging ring (depth
         # launches in flight) and the config-state cache — one context, the
-        # engine is one tenant of its device. Its descriptor elision is the
-        # launch path itself, not a side accounting.
+        # engine is one tenant of its device. The cache's elision is an
+        # account of unchanged fields; every leaf is still copied.
         # sync on the per-launch payload (sampled ids / logits / prefill
         # probe): the KV cache is donated launch-to-launch, so only the
         # per-step output is safe to block on
@@ -131,30 +152,37 @@ class ServingEngine:
         host-sampling decode (``tokens`` field → full logits)."""
         params, cache = state
         if "prefill_tokens" in desc:
-            probe, cache = self._prefill(
-                params, cache,
-                jnp.asarray(desc["prefill_tokens"]),
-                jnp.asarray(desc["positions"]),
-                jnp.asarray(desc["prefill_len"]),
-                jnp.asarray(desc["slot_mask"]),
-            )
+            args = self._to_device("prefill", desc["prefill_tokens"],
+                                   desc["positions"], desc["prefill_len"],
+                                   desc["slot_mask"])
+            with TraceAnnotation("serving.dispatch"):
+                probe, cache = self._prefill(params, cache, *args)
             return (params, cache), probe
         if self.sampling == "fused":
-            ids, cache = self._decode(
-                params, cache, self._dev_tokens,
-                jnp.asarray(desc["token_overrides"]),
-                jnp.asarray(desc["override_mask"]),
-                jnp.asarray(desc["positions"]),
-                jnp.asarray(desc["live_mask"]),
-            )
+            args = self._to_device("decode", desc["token_overrides"],
+                                   desc["override_mask"], desc["positions"],
+                                   desc["live_mask"])
+            with TraceAnnotation("serving.dispatch"):
+                ids, cache = self._decode(params, cache, self._dev_tokens, *args)
             self._dev_tokens = ids  # loopback: next launch's input tokens
             return (params, cache), ids
-        logits, cache = self._decode(
-            params, cache, jnp.asarray(desc["tokens"]),
-            jnp.asarray(desc["positions"]),
-            jnp.asarray(desc["live_mask"]),
-        )
+        args = self._to_device("decode", desc["tokens"], desc["positions"],
+                               desc["live_mask"])
+        with TraceAnnotation("serving.dispatch"):
+            logits, cache = self._decode(params, cache, *args)
         return (params, cache), logits
+
+    def _to_device(self, kind: str, *leaves) -> tuple:
+        """Copy one launch's numpy leaves to the device, counted in
+        ``h2d[kind]``."""
+        with TraceAnnotation("serving.h2d"):
+            out = tuple(jnp.asarray(x) for x in leaves)
+        copies, nbytes = len(leaves), sum(x.nbytes for x in leaves)
+        c = self.h2d[kind]
+        c.launches, c.copies, c.bytes = (c.launches + 1, c.copies + copies,
+                                         c.bytes + nbytes)
+        self._h2d_last = (copies, nbytes)
+        return out
 
     def _launch(self, desc: dict):
         """Stage one launch through the executor; adopts the new KV cache
@@ -173,13 +201,14 @@ class ServingEngine:
         same architecture (`decode_fn=`): N bridged tenant engines then pay
         a single JIT compilation instead of N. ``sampling="fused"`` returns
         the fused decode+sample step (ids out); ``"host"`` the classic
-        logits-returning step. Must match the engines' ``sampling=``."""
+        logits-returning step. Must match the engines' ``sampling=``.
+        The fused program is named ``jit_decode_and_sample`` in a profile."""
         if sampling == "fused":
-            return jax.jit(
-                functools.partial(model.decode_and_sample,
-                                  sample_backend=sample_backend),
-                donate_argnums=(1,),
-            )
+            def decode_and_sample(*args):
+                return model.decode_and_sample(*args,
+                                               sample_backend=sample_backend)
+
+            return jax.jit(decode_and_sample, donate_argnums=(1,))
         return jax.jit(model.decode_step, donate_argnums=(1,))
 
     @staticmethod
@@ -202,6 +231,7 @@ class ServingEngine:
                 f"request {req.uid}: prompt of {len(req.prompt)} tokens "
                 f"needs max_len > {len(req.prompt)} (engine max_len="
                 f"{self.max_len}) — it would overrun the KV cache")
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -212,32 +242,52 @@ class ServingEngine:
         for slot in range(self.max_slots):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
-            req = self.queue.popleft()
-            self.slot_req[slot] = req
-            self.positions[slot] = 0
-            # chunked prefill: all prompt tokens but the last stream through
-            # masked launches that advance only this slot; launches stay
-            # staged in the executor's ring (no sync), overlapping host
-            # descriptor prep with device work
-            ptoks = req.prompt[:-1]
-            for start in range(0, len(ptoks), self.prefill_chunk):
-                self._prefill_launch(slot, ptoks[start:start + self.prefill_chunk])
-            # the prompt's last token seeds the first decode step
-            self._set_token(slot, req.prompt[-1])
+            with TraceAnnotation("serving.admit") as span:
+                req = self.queue.popleft()
+                if span.is_enabled():
+                    span.set_metadata(**self._admit_args(req, slot))
+                self.slot_req[slot] = req
+                self.positions[slot] = 0
+                # chunked prefill: all prompt tokens but the last stream
+                # through masked launches that advance only this slot;
+                # launches stay staged in the executor's ring (no sync),
+                # overlapping host descriptor prep with device work
+                ptoks = req.prompt[:-1]
+                for start in range(0, len(ptoks), self.prefill_chunk):
+                    self._prefill_launch(slot, ptoks[start:start + self.prefill_chunk])
+                # the prompt's last token seeds the first decode step
+                self._set_token(slot, req.prompt[-1])
+
+    def _admit_args(self, req: Request, slot: int) -> dict:
+        return {"uid": req.uid, "slot": slot, "prompt_tokens": len(req.prompt),
+                "queued_us": (time.perf_counter() - req.submitted_at) * 1e6}
+
+    def _launch_args(self, live: list[int] | None = None) -> dict:
+        """The latest launch's copies; for a decode launch also its slot
+        occupancy and the tokens of context it attends over."""
+        copies, nbytes = self._h2d_last
+        args = {"h2d_copies": copies, "h2d_bytes": nbytes}
+        if live is not None:
+            args.update(live=len(live),
+                        context_tokens=int(sum(self.positions[s] + 1 for s in live)))
+        return args
 
     def _prefill_launch(self, slot: int, chunk: list[int]) -> None:
-        n = len(chunk)
-        buf = np.zeros((self.prefill_chunk,), np.int32)
-        buf[:n] = chunk
-        mask = np.zeros((self.max_slots,), bool)
-        mask[slot] = True
-        self._launch({
-            "prefill_tokens": buf,
-            "prefill_len": np.int32(n),
-            "positions": self.positions.copy(),
-            "slot_mask": mask,
-            **self._invariant_fields(),
-        })
+        with TraceAnnotation("serving.prefill_launch") as span:
+            n = len(chunk)
+            buf = np.zeros((self.prefill_chunk,), np.int32)
+            buf[:n] = chunk
+            mask = np.zeros((self.max_slots,), bool)
+            mask[slot] = True
+            self._launch({
+                "prefill_tokens": buf,
+                "prefill_len": np.int32(n),
+                "positions": self.positions.copy(),
+                "slot_mask": mask,
+                **self._invariant_fields(),
+            })
+            if span.is_enabled():
+                span.set_metadata(**self._launch_args())
         self.positions[slot] += n
 
     def _set_token(self, slot: int, tok: int) -> None:
@@ -253,41 +303,60 @@ class ServingEngine:
 
     def step(self) -> int:
         """One decode launch over all live slots; returns #tokens produced."""
+        with StepTraceAnnotation("serving.step", step_num=self.steps) as span:
+            self.steps += 1
+            produced, finished = self._step()
+            if span.is_enabled():
+                span.set_metadata(produced=produced, finished=finished)
+        return produced
+
+    def _step(self) -> tuple[int, int]:
+        """``step``'s work; returns the tokens produced and the requests
+        finished."""
         self._admit()
         live = self.live_slots
         if not live:
-            return 0
-        out = self._launch(self._decode_descriptor(live))
+            return 0, 0
+        with TraceAnnotation("serving.decode_launch") as span:
+            out = self._launch(self._decode_descriptor(live))
+            if self.sampling == "fused":
+                self._override_mask[:] = False  # consumed by the staged launch
+            if span.is_enabled():
+                span.set_metadata(**self._launch_args(live))
         # sampling is the synchronization point. Fused: the launch already
         # sampled on-device — block on (B,) ids, a few bytes. Host: argmax
         # here needs the full (B, vocab) logits across the boundary first.
-        if self.sampling == "fused":
-            self._override_mask[:] = False  # consumed by the staged launch
-            nxt = np.asarray(out[:, 0], np.int32)
-        else:
-            nxt = np.asarray(jnp.argmax(out[:, 0], axis=-1), np.int32)
-        produced = 0
-        for slot in live:
-            req = self.slot_req[slot]
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            self.positions[slot] += 1
-            self.tokens[slot, 0] = tok
-            produced += 1
-            hit_eos = self.eos_id is not None and tok == self.eos_id
-            if (
-                len(req.generated) >= req.max_new_tokens
-                or self.positions[slot] >= self.max_len - 1
-                or hit_eos
-            ):
-                req.done = True
-                self.finished.append(req)
-                self.slot_req[slot] = None  # slot freed for the next request
-                self.positions[slot] = 0
-                # zero the freed slot's token state: later descriptors must
-                # not carry (or dedup against) the dead request's last token
-                self._set_token(slot, 0)
-        return produced
+        with TraceAnnotation("serving.sync") as span:
+            if self.sampling == "fused":
+                nxt = np.asarray(out[:, 0], np.int32)
+            else:
+                nxt = np.asarray(jnp.argmax(out[:, 0], axis=-1), np.int32)
+            if span.is_enabled():
+                span.set_metadata(d2h_bytes=nxt.nbytes)
+        with TraceAnnotation("serving.retire"):
+            finished = 0
+            for slot in live:
+                req = self.slot_req[slot]
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                self.positions[slot] += 1
+                self.tokens[slot, 0] = tok
+                hit_eos = self.eos_id is not None and tok == self.eos_id
+                if (
+                    len(req.generated) >= req.max_new_tokens
+                    or self.positions[slot] >= self.max_len - 1
+                    or hit_eos
+                ):
+                    req.done = True
+                    self.finished.append(req)
+                    self.slot_req[slot] = None  # slot freed for the next request
+                    self.positions[slot] = 0
+                    finished += 1
+                    # zero the freed slot's token state: later descriptors
+                    # must not carry (or dedup against) the dead request's
+                    # last token
+                    self._set_token(slot, 0)
+        return len(live), finished
 
     def _decode_descriptor(self, live: list[int]) -> dict:
         """The fields that parameterize one decode launch. Copies snapshot
@@ -332,8 +401,12 @@ class ServingEngine:
         return self.max_slots * vocab * np.dtype(COMPUTE_DTYPE).itemsize
 
     def config_traffic(self) -> dict[str, float]:
-        """Config bytes sent vs. elided across all launches so far
-        (prefill and batch decode alike)."""
+        """Descriptor bytes whose values changed since the previous launch
+        ("sent") vs. unchanged ("elided"), across all launches so far
+        (prefill and batch decode alike), as ``ConfigStateCache`` counts
+        them. An account, not the copy: the launches still copy "elided"
+        leaves. The copies they make are in ``h2d`` and in each launch
+        span's ``h2d_bytes`` argument."""
         s = self.config_cache.stats
         return {
             "bytes_sent": float(s.bytes_sent),

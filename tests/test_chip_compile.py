@@ -91,6 +91,19 @@ def test_decode_and_sample_compiles(served, one_chip, chip_smoke):
     _fits_one_chip(compiled)
 
 
+def test_decode_program_is_named(served, one_chip, chip_smoke):
+    """The decode step shows in a device profile under its own name."""
+    model, params, cache = served
+    b = chip_smoke.MAX_SLOTS
+    i32, flag = (jax.ShapeDtypeStruct((b,), t, sharding=one_chip)
+                 for t in (jnp.int32, jnp.bool_))
+    prev = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    step = ServingEngine.compile_decode(model, sampling="fused",
+                                        sample_backend="pallas")
+    text = step.lower(params, cache, prev, i32, flag, i32, flag).as_text()
+    assert text.startswith("module @jit_decode_and_sample ")
+
+
 def test_prefill_chunk_compiles(served, one_chip, chip_smoke):
     model, params, cache = served
     b = chip_smoke.MAX_SLOTS
