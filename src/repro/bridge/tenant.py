@@ -97,7 +97,7 @@ class TenantEngine:
 
     def launch_dims(self, desc: dict) -> tuple[int, ...]:
         """The GEMM dims one captured launch amounts to. A chunked prefill
-        launch runs ``prefill_len`` masked decode steps, so its macro-op is
+        launch advances ``prefill_len`` tokens, so its macro-op is
         the decode tile with M scaled by the valid chunk length — the
         cluster then prices its compute honestly (``2·M·K·N``) instead of
         as a single decode step."""

@@ -162,6 +162,17 @@ def dequantize_kv(q: Array, scale: Array) -> Array:
     return q.astype(COMPUTE_DTYPE) * scale.astype(COMPUTE_DTYPE)
 
 
+def _project_qkv(params: dict, cfg: ModelConfig, x: Array, src: Array):
+    """Query heads from ``x``, key/value heads from ``src``: (B,S,H,D) each."""
+    q = x @ params["wq"]
+    k = src @ params["wk"]
+    v = src @ params["wv"]
+    if cfg.qkv_bias:
+        q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
+    return (_split_heads(q, cfg.n_heads), _split_heads(k, cfg.n_kv_heads),
+            _split_heads(v, cfg.n_kv_heads))
+
+
 def attention_apply(
     params: dict,
     cfg: ModelConfig,
@@ -174,16 +185,8 @@ def attention_apply(
     cache: dict | None = None,  # {"k","v": (B, S_max, Hkv, D)} decode cache
     cache_pos: Array | None = None,  # scalar (or (B,) vector) decode position
 ) -> tuple[Array, dict | None]:
-    hq, hkv = cfg.n_heads, cfg.n_kv_heads
-    q = x @ params["wq"]
-    src = x if kv is None else kv
-    k = src @ params["wk"]
-    v = src @ params["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
-    q = _split_heads(q, hq)
-    k = _split_heads(k, hkv)
-    v = _split_heads(v, hkv)
+    hkv = cfg.n_kv_heads
+    q, k, v = _project_qkv(params, cfg, x, x if kv is None else kv)
 
     per_slot = cache_pos is not None and getattr(cache_pos, "ndim", 0) == 1
 
@@ -257,6 +260,53 @@ def attention_apply(
     probs = jax.nn.softmax(scores, axis=-1).astype(COMPUTE_DTYPE)
     out = gqa_combine(probs, v)
     return out.reshape(b, s, -1) @ params["wo"], new_cache
+
+
+def attention_chunk(
+    params: dict,
+    cfg: ModelConfig,
+    x: Array,
+    positions: Array,
+    past: dict,
+) -> tuple[Array, dict]:
+    """Causal self-attention of one sequence's chunk of T new tokens against
+    its cached rows.
+
+    x: (1, T, d); positions: (T,) int32, consecutive, token i at
+    ``positions[i]``; past: the sequence's cache rows of this layer,
+    ``{"k","v"[,"k_scale","v_scale"]}`` each (S_max, Hkv, D|1). Query i sees
+    the cached rows before ``positions[0]`` and the chunk's tokens up to
+    itself. The chunk's K/V go through the cache's dtype (int8 quantized per
+    token, as decode stores them) before they are attended to, so each token
+    sees the keys decode would see. Returns ``(out (1, T, d), rows)`` with
+    ``rows`` the chunk's K/V in the cache's layout, (T, Hkv, D|1) each, for
+    the caller to write at ``positions``."""
+    t = x.shape[1]
+    q, k, v = _project_qkv(params, cfg, x, x)
+    q = rope(q, positions[None], cfg.rope_theta)
+    k = rope(k, positions[None], cfg.rope_theta)
+    if "k_scale" in past:
+        (k, k_scale), (v, v_scale) = quantize_kv(k), quantize_kv(v)
+        rows = {"k": k[0], "v": v[0], "k_scale": k_scale[0], "v_scale": v_scale[0]}
+        keys = jnp.concatenate([dequantize_kv(past["k"], past["k_scale"]),
+                                dequantize_kv(k[0], k_scale[0])])
+        values = jnp.concatenate([dequantize_kv(past["v"], past["v_scale"]),
+                                  dequantize_kv(v[0], v_scale[0])])
+    else:
+        rows = {"k": k[0].astype(past["k"].dtype), "v": v[0].astype(past["v"].dtype)}
+        keys = jnp.concatenate([past["k"], rows["k"]])
+        values = jnp.concatenate([past["v"], rows["v"]])
+    s = past["k"].shape[0]
+    # cached rows before the chunk, then the chunk's own tokens up to i
+    visible = jnp.concatenate([
+        jnp.broadcast_to(jnp.arange(s) < positions[0], (t, s)),
+        jnp.tril(jnp.ones((t, t), bool)),
+    ], axis=1)  # (T, S + T)
+    scores = gqa_scores(q, keys[None], cfg.n_kv_heads).astype(jnp.float32)
+    scores = jnp.where(visible[None, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(COMPUTE_DTYPE)
+    out = gqa_combine(probs, values[None])
+    return out.reshape(1, t, -1) @ params["wo"], rows
 
 
 # --------------------------------------------------------------------------

@@ -432,8 +432,10 @@ class Model:
         if cfg.family in ("dense", "vlm", "moe"):
             x, cache = self._uniform_decode(params["layers"], cache, x, positions, pos)
         elif cfg.family == "hybrid":
+            cache = self._fresh_state(cache, positions[:, 0])
             x, cache = self._hybrid_decode(params["groups"], cache, x, positions, pos)
         elif cfg.family == "ssm":
+            cache = self._fresh_state(cache, positions[:, 0])
             x, cache = self._rwkv_decode(params["layers"], cache, x)
         elif cfg.family == "encdec":
             x, cache = self._encdec_decode(params["dec_layers"], cache, x, positions, pos)
@@ -445,6 +447,24 @@ class Model:
         x = _norm(params["final_norm"], x, cfg.norm_eps)
         logits = x @ self._head(params)
         return logits, cache
+
+    @staticmethod
+    def _fresh_state(cache: dict, positions: Array) -> dict:
+        """Zero the recurrent state (hybrid ``h``/``conv``, ssm ``s``/
+        ``shift_*``) of each sequence at position 0, which has no past: a
+        slot handed to a new request must not start from the last one's
+        state. Attention rows need no reset, as decode masks the rows past a
+        sequence's position. The batch axis is as in :meth:`_masked_cache`."""
+
+        def fresh(key: str, leaf: Array) -> Array:
+            if key in ("k", "v"):
+                return leaf
+            shape = [1] * leaf.ndim
+            shape[2 if key in ("h", "conv") else 1] = positions.shape[0]
+            return jnp.where(positions.reshape(shape) > 0, leaf,
+                             jnp.zeros((), leaf.dtype))
+
+        return {k: fresh(k, v) for k, v in cache.items()}
 
     @staticmethod
     def _masked_cache(old: dict, new: dict, update_mask: Array) -> dict:
@@ -463,6 +483,29 @@ class Model:
             return jnp.where(update_mask.reshape(shape), n, o)
 
         return {k: merge(k, old[k], n) for k, n in new.items()}
+
+    @staticmethod
+    def _write_rows(buf: Array, rows: Array, slot: Array, pos0: Array,
+                    n: Array) -> Array:
+        """Write ``rows[:, :n]`` (L, T, ...) into rows ``pos0 .. pos0 + n - 1``
+        of ``buf[:, slot]`` (L, B, S, ...), in place: one dynamic-update-slice
+        of a window of min(T, S) rows, merged with what the window holds, so
+        every other row keeps its value, also where the window is clamped to
+        the cache's end."""
+        t, s = rows.shape[1], buf.shape[2]
+        w = min(t, s)
+        start = jnp.clip(pos0, 0, s - w)
+        at = (0, slot, start) + (0,) * (buf.ndim - 3)
+        old = lax.dynamic_slice(buf, at, (buf.shape[0], 1, w) + buf.shape[3:])
+        i = start + jnp.arange(w) - pos0  # the token each window row holds
+        keep = ((i < 0) | (i >= n)).reshape((1, 1, w) + (1,) * (buf.ndim - 3))
+        # window row j holds token start - pos0 + j: a slice of the rows
+        # behind t rows of padding (a gather would make the compiler lay the
+        # whole cache out anew around the write)
+        padded = jnp.concatenate([jnp.zeros_like(rows), rows], axis=1)
+        new = lax.dynamic_slice_in_dim(padded, t + start - pos0, w, axis=1)
+        return lax.dynamic_update_slice(
+            buf, jnp.where(keep, old, new[:, None].astype(buf.dtype)), at)
 
     def decode_and_sample(
         self, params: dict, cache: dict, prev_tokens: Array,
@@ -489,22 +532,85 @@ class Model:
         ids = kernel_ops.sample_op(logits[:, 0], backend=sample_backend)
         return ids[:, None].astype(jnp.int32), cache
 
+    @property
+    def prefill_path(self) -> str:
+        """How :meth:`prefill_chunk` advances a slot: ``"chunk"``, one causal
+        forward of the chunk's tokens, for the dense trunk (attention and MLP
+        layers over a k/v cache, plain or int8); ``"scan"``, one masked
+        :meth:`decode_step` per token, for the rest. MoE capacity routing
+        drops different tokens at different token counts, recurrent state
+        (hybrid, ssm) advances a token at a time, and the engine does not
+        prefill encdec or vlm front ends."""
+        return "chunk" if self.cfg.family == "dense" else "scan"
+
     def prefill_chunk(
         self, params: dict, cache: dict, chunk_tokens: Array, pos0: Array,
         n_valid: Array, slot_mask: Array,
     ) -> tuple[Array, dict]:
-        """Batched prefill: advance only the slots in ``slot_mask`` through
-        up to ``len(chunk_tokens)`` prompt tokens in **one launch** — a
-        ``lax.scan`` of masked decode steps, so a p-token prompt costs
-        ``ceil(p/chunk)`` launches instead of p full-batch launches.
+        """Batched prefill: advance the admitted slot through up to
+        ``len(chunk_tokens)`` prompt tokens in **one launch**, so a p-token
+        prompt costs ``ceil(p/chunk)`` launches instead of p full-batch
+        launches. :attr:`prefill_path` picks how; both paths leave every
+        other slot's state, and the slot's rows from ``pos0 + n_valid`` on,
+        as they were.
 
-        ``chunk_tokens``: (T,) int32, valid through ``n_valid`` (padded
-        steps are fully masked — no slot advances); ``pos0``: (B,) int32
-        per-slot start positions (step i writes at ``pos0 + i``);
-        ``slot_mask``: (B,) bool selecting the admitted slot(s). Returns
-        ``(probe, cache)`` where probe is the (B, 1) int32 argmax of the
-        last valid step for the masked slots (a few-byte sync handle for
-        the staging ring; zeros for unmasked slots)."""
+        ``chunk_tokens``: (T,) int32, valid through ``n_valid``;
+        ``pos0``: (B,) int32 per-slot start positions (token i sits at
+        ``pos0 + i``); ``slot_mask``: (B,) bool, True at the admitted slot
+        (the engine sets one). Returns ``(probe, cache)`` where probe is the
+        (B, 1) int32 argmax of the last valid token's logits in the admitted
+        slot's row (a few-byte sync handle for the staging ring; zeros
+        elsewhere)."""
+        if self.prefill_path == "chunk":
+            return self._prefill_forward(params, cache, chunk_tokens, pos0,
+                                         n_valid, slot_mask)
+        return self.prefill_scan(params, cache, chunk_tokens, pos0, n_valid,
+                                 slot_mask)
+
+    def _prefill_forward(self, params, cache, chunk_tokens, pos0, n_valid,
+                         slot_mask):
+        """The dense chunk path: the chunk's T tokens of the one admitted
+        slot go through the trunk together, reading the weights once and
+        attending over that slot's rows alone (``L.attention_chunk``). The
+        layers' new K/V rows then go into the cache in one in-place write a
+        leaf, at the slot's rows ``pos0 .. pos0 + n_valid - 1`` only
+        (:meth:`_write_rows`). The head runs on the last valid token alone."""
+        cfg = self.cfg
+        slot = jnp.argmax(slot_mask)
+        start = pos0[slot]
+        positions = start + jnp.arange(chunk_tokens.shape[0], dtype=jnp.int32)
+        x = params["embed"][chunk_tokens.astype(jnp.int32)][None]  # (1, T, d)
+
+        def body(x, xs):
+            lp, layer_cache = xs
+            h, rows = L.attention_chunk(
+                lp["attn"], cfg, _norm(lp["attn_norm"], x, cfg.norm_eps),
+                positions, {name: buf[slot] for name, buf in layer_cache.items()},
+            )
+            x = x + h
+            x = x + L.mlp_apply(lp["mlp"], _norm(lp["mlp_norm"], x, cfg.norm_eps))
+            return x, rows
+
+        x, rows = self._scan(body, x, (params["layers"], cache))
+        n_write = jnp.where(slot_mask[slot], n_valid, 0)  # no slot: no rows
+        cache = {name: self._write_rows(cache[name], rows[name], slot, start, n_write)
+                 for name in cache}
+        last = lax.dynamic_index_in_dim(x[0], jnp.maximum(n_valid - 1, 0),
+                                        keepdims=False)  # (d,)
+        logits = _norm(params["final_norm"], last, cfg.norm_eps) @ self._head(params)
+        tok = jnp.argmax(logits).astype(jnp.int32)
+        probe = jnp.where(slot_mask & (n_valid > 0), tok, 0)
+        return probe[:, None], cache
+
+    def prefill_scan(
+        self, params: dict, cache: dict, chunk_tokens: Array, pos0: Array,
+        n_valid: Array, slot_mask: Array,
+    ) -> tuple[Array, dict]:
+        """:meth:`prefill_chunk` as a ``lax.scan`` of masked decode steps:
+        step i advances the slots in ``slot_mask`` by token i when
+        ``i < n_valid`` (padded steps are fully masked). Every step computes
+        every slot, reads all the weights and selects over the whole cache;
+        the path of the families :attr:`prefill_path` names ``"scan"``."""
         b = slot_mask.shape[0]
 
         def body(carry, xs):

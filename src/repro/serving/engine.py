@@ -28,10 +28,14 @@ designs narrow that boundary:
 
 * **Batched prefill**: admission runs the prompt through
   :meth:`~repro.models.model.Model.prefill_chunk` — ``ceil(p/chunk)``
-  masked launches instead of p full-batch steps, each advancing *only* the
+  launches instead of p full-batch steps, each advancing *only* the
   admitted slot (other slots' cache rows stay bit-identical through an
-  admission). The prefill descriptor (``prefill_tokens``/``prefill_len``/
-  ``slot_mask``) is priced by the bridge like any other launch.
+  admission). On the dense trunk a launch is one causal forward of the
+  chunk's tokens that writes only the slot's new K/V rows; the other
+  families scan one masked decode step per token
+  (:attr:`~repro.models.model.Model.prefill_path`). The prefill descriptor
+  (``prefill_tokens``/``prefill_len``/``slot_mask``) is priced by the
+  bridge like any other launch.
 
 Every launch goes through a :class:`~repro.dispatch.ScheduledExecutor`
 (``engine.executor``), whose depth-bounded staging ring keeps prefill
@@ -287,7 +291,8 @@ class ServingEngine:
                 **self._invariant_fields(),
             })
             if span.is_enabled():
-                span.set_metadata(**self._launch_args())
+                span.set_metadata(path=self.model.prefill_path, valid_tokens=n,
+                                  **self._launch_args())
         self.positions[slot] += n
 
     def _set_token(self, slot: int, tok: int) -> None:

@@ -143,6 +143,16 @@ def test_one_admit_per_request(traced):
         assert len(chunks) == -(-(len(PROMPTS[s.args["uid"]]) - 1) // CHUNK)
 
 
+def test_prefill_launch_says_its_path_and_valid_tokens(traced):
+    engine, _, _, spans = traced
+    for admit in (s for s in spans if s.name == "serving.admit"):
+        chunks = [c for c in spans if c.parent is admit]
+        assert {c.args["path"] for c in chunks} == {engine.model.prefill_path} == {"chunk"}
+        valid = [c.args["valid_tokens"] for c in chunks]
+        assert sum(valid) == admit.args["prompt_tokens"] - 1
+        assert all(0 < n <= CHUNK for n in valid) and all(n == CHUNK for n in valid[:-1])
+
+
 def test_h2d_bytes_are_the_leaves_passed(traced):
     engine, _, passed, spans = traced
     launches = sorted((s for s in spans if s.name.endswith("_launch")),
