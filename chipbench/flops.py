@@ -1,5 +1,6 @@
 """Operations and bytes the served work needs, from a configuration's
-shapes alone, whatever code computes it.
+shapes alone, whatever code computes it. The layer formulas are the dense
+family's; ``chipbench/reference/dense.py`` states them as its model FLOPs.
 
 A multiply-add counts as two operations. Model FLOPs count the weight
 matrix products and the attention products (scores and the weighted sum)

@@ -7,9 +7,21 @@ metric is a file found by its name in ``BENCHMARK.json``:
   repo's id for it and the engine settings;
 * ``chipbench/traffic/<mix>.json``: the traffic parameters
   (``chipbench.traffic`` reads them);
-* ``chipbench/metrics/<metric>.py``: a reader with ``read(run)`` that
-  returns the metric's value from the trace and counters, or ``None``;
-* ``chipbench/reference/<family>.py``: the plain reference of a family.
+* ``chipbench/metrics/<quantity>.py``: a reader with ``read(run)`` that
+  returns the metric's value from the trace and counters, or ``None``.
+  A metric's quantity is its name up to the first ``.``: a quantity split
+  by cell, as ``prefill_ms_per_token.open_loop`` is, is read alike under
+  each name, each with its own bound or ``moves``;
+* ``chipbench/reference/<family>.py``: everything the benchmark knows of
+  a model family. It states the plain reference ``<Family>Reference``;
+  ``DRAWS``, the weight draws of the family's own leaves, by leaf name
+  (``chipbench.weights`` draws the shared ones); ``WIDTHS``, the family's
+  own widths, by configuration-file key, each with the ``ModelConfig``
+  attribute it is checked against; ``ROTARY``, whether its attention
+  rotates (a configuration file of a family that rotates states
+  ``rope_theta``); and ``prefill_flops(cfg, prompt_len)`` and
+  ``decode_flops(cfg, contexts)``, the family's model FLOPs
+  (``chipbench.flops`` counts the dense family's).
 
 The system under test is ``ServingEngine`` (``src/repro/serving``): the
 window calls ``submit`` and loops on ``step`` in this process.
@@ -18,6 +30,7 @@ window calls ``submit`` and loops on ``step`` in this process.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -29,7 +42,7 @@ from pathlib import Path
 
 import numpy as np
 
-from chipbench import check, flops
+from chipbench import check
 from chipbench.traffic import Traffic
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -43,6 +56,8 @@ TRACE_SLICE_S = 3.0
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# what a family file has to state beside its reference class
+FAMILY_STATES = ("DRAWS", "WIDTHS", "ROTARY", "prefill_flops", "decode_flops")
 
 
 class NoChip(RuntimeError):
@@ -62,8 +77,13 @@ def mix_file(name: str) -> Path:
     return HERE / "traffic" / f"{name}.json"
 
 
+def quantity(metric: str) -> str:
+    """What a metric measures: its name up to the first ``.``."""
+    return metric.split(".", 1)[0]
+
+
 def reader_file(metric: str) -> Path:
-    return HERE / "metrics" / f"{metric}.py"
+    return HERE / "metrics" / f"{quantity(metric)}.py"
 
 
 def load_module(path: Path, name: str):
@@ -77,14 +97,28 @@ def load_module(path: Path, name: str):
 
 def load_reader(metric: str):
     """The ``read`` function of a per-layer metric."""
-    return load_module(reader_file(metric), f"chipbench_metric_{metric}").read
+    return load_module(reader_file(metric),
+                       f"chipbench_metric_{quantity(metric)}").read
+
+
+def load_family(family: str):
+    """The module ``chipbench/reference/<family>.py``, loaded once; an
+    error where it does not state all of ``FAMILY_STATES``."""
+    return _load_family(HERE / "reference" / f"{family}.py")
+
+
+@functools.cache
+def _load_family(path: Path):
+    mod = load_module(path, f"chipbench_reference_{path.stem}")
+    missing = [n for n in FAMILY_STATES if not hasattr(mod, n)]
+    if missing:
+        raise ValueError(f"{path} does not state {', '.join(missing)}")
+    return mod
 
 
 def load_reference(family: str):
     """The plain reference class of a model family."""
-    mod = load_module(HERE / "reference" / f"{family}.py",
-                      f"chipbench_reference_{family}")
-    return getattr(mod, f"{family.capitalize()}Reference")
+    return getattr(load_family(family), f"{family.capitalize()}Reference")
 
 
 @dataclass
@@ -110,7 +144,9 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
         raise KeyError(f"no workload {name!r} in {bench_path}; "
                        f"known: {sorted(cells)}")
     w = cells[name]
-    return Cell(name, w["config"], load_json(config_file(w["config"])),
+    config = load_json(config_file(w["config"]))
+    load_family(config["family"])
+    return Cell(name, w["config"], config,
                 w["traffic"], load_json(mix_file(w["traffic"])), int(w["chips"]),
                 _for_cell(bench["end_to_end"], name),
                 _for_cell(bench["per_layer"], name))
@@ -166,14 +202,22 @@ class CompileCounter:
 
 def program_config(cfg: dict):
     """The program's ``ModelConfig`` for a configuration file: the repo's
-    entry, run with the file's rotary base and norm epsilon, and checked
-    against the file's widths."""
+    entry, run with the file's norm epsilon and, where the file states
+    one, its rotary base, and checked against the file's widths: those of
+    every family and the family's own ``WIDTHS``. Only a family whose
+    attention does not rotate (``ROTARY`` false) may leave the rotary base
+    out; the repo entry's then stands."""
     from repro.configs import get
 
-    mc = dataclasses.replace(get(cfg["repo_config"]),
-                             rope_theta=float(cfg["rope_theta"]),
-                             norm_eps=float(cfg["rms_norm_eps"]),
-                             remat="none")
+    fam = load_family(cfg["family"])
+    mc = get(cfg["repo_config"])
+    theta = cfg.get("rope_theta")
+    if theta is None and fam.ROTARY:
+        raise ValueError(f"{cfg['repo_config']}: the {cfg['family']} family's "
+                         "attention rotates, and the file states no rope_theta")
+    mc = dataclasses.replace(
+        mc, rope_theta=mc.rope_theta if theta is None else float(theta),
+        norm_eps=float(cfg["rms_norm_eps"]), remat="none")
     want = {"n_layers": cfg["num_hidden_layers"], "d_model": cfg["hidden_size"],
             "n_heads": cfg["num_attention_heads"],
             "n_kv_heads": cfg["num_key_value_heads"],
@@ -181,6 +225,7 @@ def program_config(cfg: dict):
             "head_dim_": cfg["head_dim"], "family": cfg["family"],
             "tie_embeddings": cfg["tie_word_embeddings"],
             "qkv_bias": cfg["qkv_bias"]}
+    want.update({attr: cfg[key] for key, attr in fam.WIDTHS.items()})
     got = {k: getattr(mc, k) for k in want}
     if got != want:
         raise ValueError(f"program config {cfg['repo_config']} {got} "
@@ -225,6 +270,7 @@ class Run:
         self.seed = int(seed)
         self.seconds = float(seconds)
         self.trace = bool(trace)
+        self.family = load_family(cell.config["family"])
         self.model_cfg = model_cfg or program_config(cell.config)
         self.engine_hook = engine_hook
         self.limit = cell.config["correct"]["max_logit_gap"]
@@ -248,7 +294,8 @@ class Run:
         max_len = self.cell.mix["engine"]["max_len"]
         model = Model(self.model_cfg)
         self.params = make_params(
-            jax.eval_shape(model.init, jax.random.key(0)), self.seed)
+            jax.eval_shape(model.init, jax.random.key(0)), self.seed,
+            self.family.DRAWS)
         self.engine = ServingEngine(
             model, self.params, max_slots=slots, max_len=max_len,
             sampling=eng["sampling"], sample_backend=eng["sample_backend"],
@@ -271,6 +318,7 @@ class Run:
         import jax
 
         eng, c, cfg = self.engine, self.counters, self.cell.config
+        fam = self.family
         admit, prefill, decode = eng._admit, eng._prefill, eng._decode
 
         def admit_w():
@@ -282,7 +330,7 @@ class Run:
                     n = len(r.prompt)
                     c.admitted_prompt_tokens += n
                     c.prefill_tokens += n - 1
-                    c.prefill_flops += flops.prefill_flops(cfg, n)
+                    c.prefill_flops += fam.prefill_flops(cfg, n)
 
         def prefill_w(*args):
             with jax.profiler.TraceAnnotation("chipbench.prefill_launch"):
@@ -291,7 +339,7 @@ class Run:
         def decode_w(*args):
             live = eng.live_slots
             c.decode_launches += 1
-            c.decode_flops += flops.decode_flops(
+            c.decode_flops += fam.decode_flops(
                 cfg, [int(eng.positions[s]) + 1 for s in live])
             with jax.profiler.TraceAnnotation("chipbench.decode_launch"):
                 return decode(*args)
@@ -437,8 +485,9 @@ class Run:
 
     def end_to_end(self) -> dict:
         values = self.client_values()
-        return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
-                for m in self.cell.end_to_end if values.get(m["name"]) is not None}
+        return {m["name"]: {"value": values[quantity(m["name"])], "unit": m["unit"]}
+                for m in self.cell.end_to_end
+                if values.get(quantity(m["name"])) is not None}
 
     def per_layer(self, peaks: dict) -> dict:
         out = {}
@@ -515,8 +564,16 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     run = Run(cell, seed, seconds, trace, **run_kw)
     with CompileCounter() as setup_counter:
         run.setup()
+    # set-up's garbage is collected in set-up's time, and what set-up made,
+    # which lives to the end of the run, is frozen out of the collector's
+    # scans while the window runs
+    gc.collect()
+    gc.freeze()
     run.setup_s = time.perf_counter() - t_start
-    run.window()
+    try:
+        run.window()
+    finally:
+        gc.unfreeze()
     late = sorted(run.late_s) or [0.0]
     print(f"window: {run.window_s:.3f} s, {len(run.records)} requests sent, "
           f"generator late by median {percentile(late, 50) * 1e3:.3f} ms, "

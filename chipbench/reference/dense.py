@@ -1,4 +1,5 @@
-"""Plain float32 reference of the dense decoder family.
+"""The dense decoder family: its plain float32 reference, weight draws,
+widths and model FLOPs.
 
 Written from the published description (pre-norm RMSNorm decoder, grouped
 query attention with rotary positions in the rotate-half form, optional
@@ -13,6 +14,12 @@ drew, upcast to float32 layer by layer.
 both operands rounded to float8 e4m3 (per-row scales for activations,
 per-output-channel scales for weights), the step below the bfloat16 the
 configurations state. Attention and norms stay float32.
+
+Every dense leaf has a shared draw (``chipbench.weights``) and every dense
+width is one the harness checks for any family, so ``DRAWS`` and
+``WIDTHS`` are empty; the attention rotates (``ROTARY``), so a dense
+configuration file states ``rope_theta``; the model FLOPs are
+``chipbench.flops``' count.
 """
 
 from __future__ import annotations
@@ -22,6 +29,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.flops import decode_flops, prefill_flops  # noqa: F401
+
+DRAWS: dict = {}
+WIDTHS: dict = {}
+ROTARY = True
 
 FP8_MAX = 448.0  # largest finite float8 e4m3 value
 VOCAB_BLOCK = 16384

@@ -1,4 +1,5 @@
-"""Seeded random weights for a dense decoder, made on the device.
+"""Seeded random weights, made on the device: shared draws plus the
+family's.
 
 The benchmark makes the weights itself, so that the reference it compares
 with takes nothing the program under test has made. They are laid out as
@@ -6,9 +7,11 @@ the program's parameter tree (``layout``: shapes and dtypes only, from
 ``jax.eval_shape`` of the program's init) and drawn in one jitted call,
 directly in each leaf's served dtype.
 
-Draws, by leaf name: matrices N(0, 1/fan_in); the embedding N(0,
-1/hidden_size), so that tied logits have a spread of about 1; q/k/v biases
-N(0, 0.25); norm weights 1 + N(0, 0.01).
+Shared draws, by leaf name, for any family: matrices and the untied output
+head N(0, 1/fan_in); the embedding N(0, 1/hidden_size), so that tied
+logits have a spread of about 1; q/k/v biases N(0, 0.25); norm weights
+1 + N(0, 0.01). A family's own leaves are drawn by the ``DRAWS`` of its
+file ``chipbench/reference/<family>.py``.
 """
 
 from __future__ import annotations
@@ -16,8 +19,28 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-MATRICES = ("wq", "wk", "wv", "wo", "wi", "wg")
-BIASES = ("bq", "bk", "bv")
+
+def _matrix(key, shape, dtype):
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(
+        shape[-2] ** -0.5, dtype)
+
+
+def _embed(key, shape, dtype):
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(
+        shape[-1] ** -0.5, dtype)
+
+
+def _bias(key, shape, dtype):
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(0.5, dtype)
+
+
+def _norm(key, shape, dtype):
+    return 1 + 0.1 * jax.random.normal(key, shape, dtype)
+
+
+SHARED = {"embed": _embed, "head": _matrix,
+          **dict.fromkeys(("wq", "wk", "wv", "wo", "wi", "wg"), _matrix),
+          **dict.fromkeys(("bq", "bk", "bv"), _bias)}
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -26,31 +49,35 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def _leaf(key, path: tuple[str, ...], shape, dtype):
+def _draw_for(path: tuple[str, ...], draws: dict):
+    """The draw of a leaf: the shared table's, else the family's."""
     name = path[-1]
-    if name == "embed":
-        return jax.random.normal(key, shape, dtype) * jnp.asarray(
-            shape[-1] ** -0.5, dtype)
-    if name in MATRICES:
-        return jax.random.normal(key, shape, dtype) * jnp.asarray(
-            shape[-2] ** -0.5, dtype)
-    if name in BIASES:
-        return jax.random.normal(key, shape, dtype) * jnp.asarray(0.5, dtype)
-    if name == "w" and path[-2].endswith("norm"):
-        return 1 + 0.1 * jax.random.normal(key, shape, dtype)
-    raise ValueError(f"no draw for parameter {'/'.join(path)}")
+    if name == "w" and len(path) > 1 and path[-2].endswith("norm"):
+        return _norm
+    draw = SHARED.get(name, draws.get(name))
+    if draw is None:
+        raise ValueError(f"no draw for parameter {'/'.join(path)}")
+    return draw
 
 
-def make_params(layout, seed: int):
+def make_params(layout, seed: int, draws: dict | None = None):
     """Parameters shaped like ``layout``, drawn from ``seed`` on the
-    default device in one compiled call."""
+    default device in one compiled call. ``draws`` maps the family's own
+    leaf names to ``draw(key, shape, dtype)``; a name the shared table
+    draws is an error, since that entry could never be used."""
+    draws = draws or {}
+    taken = sorted(set(draws) & set(SHARED))
+    if taken:
+        raise ValueError(f"family draws for {', '.join(taken)}: the shared "
+                         "table draws these leaves")
     leaves, treedef = jax.tree_util.tree_flatten_with_path(layout)
     paths = [tuple(k.key for k in p) for p, _ in leaves]
+    fns = [_draw_for(path, draws) for path in paths]
 
     @jax.jit
     def draw(key):
         keys = jax.random.split(key, len(leaves))
-        return [_leaf(k, path, s.shape, s.dtype)
-                for k, path, (_, s) in zip(keys, paths, leaves)]
+        return [fn(k, s.shape, s.dtype)
+                for fn, k, (_, s) in zip(fns, keys, leaves)]
 
     return jax.tree_util.tree_unflatten(treedef, draw(seed_key(seed)))

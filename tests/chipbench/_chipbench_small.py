@@ -10,21 +10,23 @@ from chipbench import harness  # noqa: E402
 from repro.configs import get  # noqa: E402
 
 E2E = [("output_tok_s", "tokens/s"), ("ttft_p50_ms", "ms"), ("itl_p99_ms", "ms"),
-       ("setup_s", "s")]
+       ("ttft_p50_ms.open_loop", "ms"), ("setup_s", "s")]
 
 
 def small_config(name: str):
     """The configuration file of ``name`` at the repo's ``.reduced()``
-    widths, with the Pallas kernel in interpret mode, and the matching
-    program configuration."""
-    mc = get(harness.load_json(harness.config_file(name))["repo_config"]).reduced()
+    widths, the family's own among them, with the Pallas kernel in
+    interpret mode, and the matching program configuration."""
     cfg = harness.load_json(harness.config_file(name))
+    mc = get(cfg["repo_config"]).reduced()
     cfg.update(hidden_size=mc.d_model, intermediate_size=mc.d_ff,
                num_hidden_layers=mc.n_layers, num_attention_heads=mc.n_heads,
                num_key_value_heads=mc.n_kv_heads, head_dim=mc.head_dim_,
                vocab_size=mc.vocab_size)
+    cfg.update({key: getattr(mc, attr) for key, attr
+                in harness.load_family(cfg["family"]).WIDTHS.items()})
     cfg["engine"] = dict(cfg["engine"], sample_backend="pallas_interpret")
-    mc = dataclasses.replace(mc, rope_theta=cfg["rope_theta"],
+    mc = dataclasses.replace(mc, rope_theta=cfg.get("rope_theta", mc.rope_theta),
                              norm_eps=cfg["rms_norm_eps"], remat="none")
     return cfg, mc
 

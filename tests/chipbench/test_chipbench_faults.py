@@ -4,6 +4,7 @@ passes, and each fault a serving cell can have (``chipbench.faults``),
 planted in the timed path after warm-up, comes out not correct. So does the control: the float32
 reference computed in fp8, read at the served positions."""
 
+import gc
 import sys
 import time
 from pathlib import Path
@@ -46,6 +47,25 @@ def test_sound_run_is_correct_and_prints_its_numbers():
     assert res["checks"]["window_compiles"]["value"] == 0
     assert res["attempted"] >= 1 and res["failed"] == 0
     assert set(res["metrics"]) >= {"output_tok_s", "ttft_p50_ms", "setup_s"}
+    # a quantity split by cell reads the same under each of its names
+    m = res["metrics"]
+    assert m["ttft_p50_ms.open_loop"]["value"] == m["ttft_p50_ms"]["value"]
+
+
+def test_the_window_runs_with_the_set_up_kept_out_of_the_collector(monkeypatch):
+    """Set-up's objects are frozen out of the collector for the window
+    alone: a full collection there scans only what the window made."""
+    frozen = []
+    window = harness.Run.window
+
+    def watched(run):
+        frozen.append(gc.get_freeze_count())
+        window(run)
+
+    monkeypatch.setattr(harness.Run, "window", watched)
+    assert _execute()["correct"] is True
+    assert frozen[0] > 10_000
+    assert gc.get_freeze_count() < 1_000  # unfrozen once the window closed
 
 
 def test_prefill_chunks_first_tokens_are_kept_for_the_check():
