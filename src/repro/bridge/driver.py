@@ -168,9 +168,10 @@ class ClosedLoopDriver:
             produced, descs = te.step()
             total += 1
             assert total <= max_steps, f"closed loop exceeded {max_steps} steps"
-            if not descs:
-                # a step that launched nothing means the engine drained
-                # (live slots and queue both empty) — retire the tenant
+            if not descs and not produced:
+                # a step that launched and read nothing means the engine
+                # drained (live slots and queue both empty, nothing
+                # unread) — retire the tenant
                 assert te.done, f"{name} stepped without launching or draining"
                 continue
             t = now
@@ -185,6 +186,10 @@ class ClosedLoopDriver:
                 cfg += rec.config_cycles
                 exposed += rec.exposed_config
                 comp += rec.end - rec.start
+            if host is None:
+                # a step that only read a launch staged ahead in the step
+                # before: its sync crosses the link of the tenant's home
+                host = self.cluster.router.home(name)
             # feedback edge: the host blocks on the step's sampling sync
             # before it can release this tenant's next step
             rb = self._readback_cycles(te, host.link if host else None)

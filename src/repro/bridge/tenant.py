@@ -79,19 +79,23 @@ class TenantEngine:
 
     @property
     def done(self) -> bool:
-        """No queued requests and no live slots — the engine has drained."""
-        return not (self.engine.queue or self.engine.live_slots)
+        """No queued requests, no live slots and no launch left unread —
+        the engine has drained."""
+        return self.engine.idle
 
     def step(self) -> tuple[int, list[dict]]:
         """Advance the engine one continuous-batching step and hand back
         the launch descriptors it actually issued (possibly several: an
-        admission's prefill launches ride ahead of the decode launch)."""
+        admission's prefill launches ride ahead of the decode launch, and
+        a decode launch may be staged ahead of the read of the one before).
+        A step that only read a launch staged ahead in the step before
+        issues none, and still produces that launch's tokens."""
         produced = self.engine.step()
         # drain in place: the engine's observer holds this very list
         descs = list(self._pending)
         self._pending.clear()
         self.tokens += produced
-        self.steps += 1 if descs else 0
+        self.steps += 1 if descs or produced else 0
         self.launches += len(descs)
         return produced, descs
 

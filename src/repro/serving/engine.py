@@ -37,6 +37,21 @@ designs narrow that boundary:
   (``prefill_tokens``/``prefill_len``/``slot_mask``) is priced by the
   bridge like any other launch.
 
+* **A decode launch staged ahead** (fused sampling): where the next decode
+  launch's slots and positions are fixed without the ids of the one in
+  flight, ``step()`` dispatches it before reading those ids, and reads them
+  while it runs. This is the paper's *concurrent configuration*: the host's
+  turn (descriptor, copies, dispatch, retirement) hides behind the device's
+  step instead of following it. It holds when every slot is live, no
+  request waits, and no request receives its last token by length in the
+  launch in flight; positions advance at launch, one per decode launch for
+  each live slot. Only an EOS is learned one launch late: the row decoded
+  past it goes to no request (``ahead.dropped_rows``). Staging does not
+  engage with a free slot or a waiting request (an arrival would wait one
+  more decode step), after a launch that ends a request by length, or
+  under host sampling, whose ids cross through the host: those steps
+  launch, read and retire in turn, as the *sequential configuration* does.
+
 Every launch goes through a :class:`~repro.dispatch.ScheduledExecutor`
 (``engine.executor``), whose depth-bounded staging ring keeps prefill
 launches in flight while the host prepares the next one — the serving twin
@@ -75,6 +90,26 @@ class Request:
     generated: list[int] = field(default_factory=list)
     done: bool = False
     submitted_at: float = 0.0  # time.perf_counter() at submit()
+
+
+@dataclass
+class AheadCount:
+    """Decode launches staged ahead of the read of the launch before them,
+    and the rows those launches decoded for a request that had by then
+    ended on an EOS (read, and given to no request)."""
+
+    launches: int = 0
+    dropped_rows: int = 0
+
+
+@dataclass
+class _DecodeLaunch:
+    """A decode launch whose ids are not read yet: its output, and for
+    each slot it decoded, the request it decoded for and whether it holds
+    that request's last token by length."""
+
+    out: object
+    rows: list[tuple[int, Request, bool]]
 
 
 @dataclass
@@ -124,6 +159,9 @@ class ServingEngine:
         self.steps = 0
         self.h2d = {"prefill": H2DCount(), "decode": H2DCount()}
         self._h2d_last = (0, 0)  # the latest launch's (copies, bytes)
+        self.ahead = AheadCount()
+        # the decode launch staged ahead in the last step, read in the next
+        self._unread: _DecodeLaunch | None = None
         # decode_fn/prefill_fn let N engines of one model share a single
         # compiled step (the bridge runs many tenant engines of the same
         # architecture; each call still passes its own donated cache). A
@@ -168,6 +206,9 @@ class ServingEngine:
                                    desc["live_mask"])
             with TraceAnnotation("serving.dispatch"):
                 ids, cache = self._decode(params, cache, self._dev_tokens, *args)
+            # the read-back starts when the program ends, and needs no
+            # program of its own to queue behind a launch staged after it
+            ids.copy_to_host_async()
             self._dev_tokens = ids  # loopback: next launch's input tokens
             return (params, cache), ids
         args = self._to_device("decode", desc["tokens"], desc["positions"],
@@ -266,14 +307,16 @@ class ServingEngine:
         return {"uid": req.uid, "slot": slot, "prompt_tokens": len(req.prompt),
                 "queued_us": (time.perf_counter() - req.submitted_at) * 1e6}
 
-    def _launch_args(self, live: list[int] | None = None) -> dict:
-        """The latest launch's copies; for a decode launch also its slot
-        occupancy and the tokens of context it attends over."""
+    def _launch_args(self, desc: dict | None = None) -> dict:
+        """The latest launch's copies; for a decode launch, given its
+        descriptor, also its slot occupancy and the tokens of context it
+        attends over."""
         copies, nbytes = self._h2d_last
         args = {"h2d_copies": copies, "h2d_bytes": nbytes}
-        if live is not None:
-            args.update(live=len(live),
-                        context_tokens=int(sum(self.positions[s] + 1 for s in live)))
+        if desc is not None:
+            live = desc["live_mask"]
+            args.update(live=int(live.sum()),
+                        context_tokens=int((desc["positions"][live] + 1).sum()))
         return args
 
     def _prefill_launch(self, slot: int, chunk: list[int]) -> None:
@@ -307,7 +350,10 @@ class ServingEngine:
     # ----------------------------------------------------------------- step
 
     def step(self) -> int:
-        """One decode launch over all live slots; returns #tokens produced."""
+        """One continuous-batching step; returns the tokens it read. It
+        admits what fits, reads one decode launch (its own, or the one the
+        last step staged ahead) and, where the next launch is fixed without
+        those ids, stages that launch before the read."""
         with StepTraceAnnotation("serving.step", step_num=self.steps) as span:
             self.steps += 1
             produced, finished = self._step()
@@ -319,39 +365,78 @@ class ServingEngine:
         """``step``'s work; returns the tokens produced and the requests
         finished."""
         self._admit()
-        live = self.live_slots
-        if not live:
-            return 0, 0
+        launch, self._unread = self._unread, None
+        if launch is None:
+            live = self.live_slots
+            if not live:
+                return 0, 0
+            launch = self._decode_launch(live)
+        if self._stages_after(launch):
+            self._unread = self._decode_launch([s for s, _, _ in launch.rows],
+                                               ahead=True)
+        return self._read(launch)
+
+    def _stages_after(self, launch: _DecodeLaunch) -> bool:
+        """Whether the decode launch after ``launch`` is fixed without its
+        ids: fused sampling, no request waiting, every slot still holding
+        the request ``launch`` decoded for, and none of them receiving its
+        last token by length in ``launch``."""
+        return (self.sampling == "fused" and not self.queue
+                and len(launch.rows) == self.max_slots
+                and all(self.slot_req[s] is req and not last
+                        for s, req, last in launch.rows))
+
+    def _decode_launch(self, live: list[int], ahead: bool = False) -> _DecodeLaunch:
+        """Dispatch one decode launch over ``live``; its ids stay unread.
+        Each live slot's position advances by one, so the next launch can
+        be built before these ids are read."""
         with TraceAnnotation("serving.decode_launch") as span:
-            out = self._launch(self._decode_descriptor(live))
+            desc = self._decode_descriptor(live)
+            out = self._launch(desc)
             if self.sampling == "fused":
                 self._override_mask[:] = False  # consumed by the staged launch
+            self.positions[live] += 1
+            self.ahead.launches += ahead
             if span.is_enabled():
-                span.set_metadata(**self._launch_args(live))
+                span.set_metadata(ahead=ahead, **self._launch_args(desc))
+        return _DecodeLaunch(out, [(s, self.slot_req[s], self._last_by_length(s))
+                                   for s in live])
+
+    def _last_by_length(self, slot: int) -> bool:
+        """Whether the decode launch just dispatched holds the last token
+        that the slot's request may have by length: its ``max_new_tokens``
+        th, or the one at the last row of the slot."""
+        req, pos = self.slot_req[slot], int(self.positions[slot])
+        return pos - len(req.prompt) + 1 >= req.max_new_tokens or pos >= self.max_len - 1
+
+    def _read(self, launch: _DecodeLaunch) -> tuple[int, int]:
+        """Read one decode launch's ids and retire them against the
+        requests it was launched for."""
         # sampling is the synchronization point. Fused: the launch already
-        # sampled on-device — block on (B,) ids, a few bytes. Host: argmax
-        # here needs the full (B, vocab) logits across the boundary first.
+        # sampled on-device and its ids are on their way to the host — block
+        # on (B,) ids, a few bytes. Host: argmax here needs the full
+        # (B, vocab) logits across the boundary first.
         with TraceAnnotation("serving.sync") as span:
             if self.sampling == "fused":
-                nxt = np.asarray(out[:, 0], np.int32)
+                nxt = np.asarray(launch.out)[:, 0]
             else:
-                nxt = np.asarray(jnp.argmax(out[:, 0], axis=-1), np.int32)
+                nxt = np.asarray(jnp.argmax(launch.out[:, 0], axis=-1), np.int32)
             if span.is_enabled():
                 span.set_metadata(d2h_bytes=nxt.nbytes)
         with TraceAnnotation("serving.retire"):
-            finished = 0
-            for slot in live:
-                req = self.slot_req[slot]
+            produced = finished = 0
+            for slot, req, last in launch.rows:
+                if req.done:
+                    # decoded past the EOS that the launch before sampled:
+                    # the row goes to no request, and the slot's next
+                    # occupant starts from its own prefill and override
+                    self.ahead.dropped_rows += 1
+                    continue
                 tok = int(nxt[slot])
                 req.generated.append(tok)
-                self.positions[slot] += 1
+                produced += 1
                 self.tokens[slot, 0] = tok
-                hit_eos = self.eos_id is not None and tok == self.eos_id
-                if (
-                    len(req.generated) >= req.max_new_tokens
-                    or self.positions[slot] >= self.max_len - 1
-                    or hit_eos
-                ):
+                if last or (self.eos_id is not None and tok == self.eos_id):
                     req.done = True
                     self.finished.append(req)
                     self.slot_req[slot] = None  # slot freed for the next request
@@ -361,7 +446,7 @@ class ServingEngine:
                     # must not carry (or dedup against) the dead request's
                     # last token
                     self._set_token(slot, 0)
-        return len(live), finished
+        return produced, finished
 
     def _decode_descriptor(self, live: list[int]) -> dict:
         """The fields that parameterize one decode launch. Copies snapshot
@@ -419,9 +504,14 @@ class ServingEngine:
             "elision_ratio": s.elision_ratio,
         }
 
+    @property
+    def idle(self) -> bool:
+        """No request queued or live, and no decode launch left unread."""
+        return not (self.queue or self.live_slots or self._unread is not None)
+
     def run_until_done(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0
-        while (self.queue or self.live_slots) and steps < max_steps:
+        while not self.idle and steps < max_steps:
             self.step()
             steps += 1
         self.executor.drain()  # retire any still-staged launches

@@ -255,3 +255,32 @@ def test_overlapped_cluster_raises_token_goodput_bit_identically(small_model):
     # byte accounting is untouched by overlap — parity still exact
     assert all(p["matched"] for p in ov.config_parity().values())
     assert ov.cluster.bytes_sent == ser.cluster.bytes_sent
+
+
+def test_bridged_tokens_bit_identical_with_launches_staged_ahead(small_model):
+    """Four requests on four slots: the engine stages decode launches
+    ahead of their reads, so one step issues two decode launches and the
+    last step only reads. The bridge mirrors every launch, perturbs no
+    token, keeps its byte accounting exact, and records every token on
+    the step that read it."""
+    def engine():
+        eng = _engine(small_model)
+        eng.submit(Request(uid=len(PROMPTS), prompt=[2, 6], max_new_tokens=5))
+        return eng
+
+    standalone = engine()
+    want = _tokens(standalone.run_until_done())
+    assert standalone.ahead.launches > 0
+
+    bridged = engine()
+    tenant = TenantEngine("t0", bridged, accel="opengemm")
+    cluster = Cluster.uniform(1, {"opengemm": 1}, policy="affinity",
+                              sticky=True, link="noc")
+    rep = ClosedLoopDriver([tenant], cluster).run()
+    assert _tokens(bridged.finished) == want
+    assert bridged.ahead.launches == standalone.ahead.launches
+    steps = [s for s in rep.steps if s.tenant == "t0"]
+    assert [s.launches for s in steps].count(0) == 1  # the last read
+    assert rep.tokens == sum(s.tokens for s in steps) == 5 * len(want)
+    assert tenant.launches == rep.cluster.launches
+    assert all(p["matched"] for p in rep.config_parity().values())

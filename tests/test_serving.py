@@ -252,3 +252,144 @@ def test_int8_cache_halves_bytes(small_model):
     quant = nbytes(model_q.init_cache(4, 128))
     # int8 + bf16 scales (D=16 heads → scale overhead 2/16): ≈ 0.56×
     assert quant < 0.65 * full
+
+
+# ------------------------------------------- a decode launch staged ahead
+
+# two requests on two slots, long answers, so that most decode launches
+# are staged ahead of the read of the one before; and the streams that the
+# engine served for them when it read every launch before the next
+STAGED = {0: ([5, 9, 2, 7, 1, 4], 24), 1: ([3, 1, 4], 30)}
+SYNC_STREAMS = {
+    0: [232, 194, 232, 194, 232, 223, 207, 171, 194, 232, 232, 232, 223, 223,
+        205, 205, 124, 147, 147, 201, 201, 201, 147, 205],
+    1: [223, 236, 223, 223, 62, 54, 253, 164, 219, 1, 136, 136, 205, 136, 205,
+        136, 205, 81, 70, 219, 219, 219, 83, 83, 83, 202, 255, 255, 36, 223],
+}
+FAMILY_ARCH = {"dense": "qwen2-0.5b", "moe": "phi3.5-moe-42b-a6.6b",
+               "hybrid": "jamba-1.5-large-398b", "ssm": "rwkv6-7b"}
+
+
+def _staged_engine(model, params, **kw):
+    engine = ServingEngine(model, params, max_slots=2, max_len=64,
+                           prefill_chunk=4, **kw)
+    reqs = [Request(uid=uid, prompt=list(p), max_new_tokens=n)
+            for uid, (p, n) in STAGED.items()]
+    for r in reqs:
+        engine.submit(r)
+    return engine, reqs
+
+
+def test_staged_streams_bit_identical_to_host_and_synchronous(small_model):
+    cfg, model, params = small_model
+    engine, _ = _staged_engine(model, params)
+    got = {r.uid: r.generated for r in engine.run_until_done()}
+    # while both slots are live, every launch but the first is staged
+    # ahead: 23 of the first 24; the last 6 serve request 1 alone
+    assert engine.h2d["decode"].launches == 30
+    assert engine.ahead.launches == 23 and engine.ahead.dropped_rows == 0
+    host, _ = _staged_engine(model, params, sampling="host")
+    assert {r.uid: r.generated for r in host.run_until_done()} == got
+    assert host.ahead.launches == 0  # host sampling stays synchronous
+    assert got == SYNC_STREAMS
+
+
+def test_no_launch_is_staged_with_a_free_slot_or_a_waiting_request(small_model):
+    cfg, model, params = small_model
+    engine = ServingEngine(model, params, max_slots=2, max_len=64)
+    engine.submit(Request(uid=0, prompt=[5, 9, 2], max_new_tokens=12))
+    engine.run_until_done()
+    assert engine.ahead.launches == 0  # one of two slots live
+
+    engine = ServingEngine(model, params, max_slots=2, max_len=64)
+    for uid, n in enumerate([4, 12, 12]):
+        engine.submit(Request(uid=uid, prompt=[5 + uid, 2], max_new_tokens=n))
+    while not engine.idle:
+        before = engine.ahead.launches
+        engine.step()
+        if engine.queue:  # a request waited through the step
+            assert engine.ahead.launches == before
+    assert engine.ahead.launches > 0  # once none waits
+
+
+@pytest.mark.parametrize("max_len", [64, 10])
+def test_no_launch_is_staged_after_a_last_token_by_length(small_model, max_len):
+    """Every row a decode launch computes goes to a request: none is
+    staged after a launch that holds a request's last token, whether by
+    ``max_new_tokens`` or by the slot's last row."""
+    cfg, model, params = small_model
+    captured = []
+    engine = ServingEngine(model, params, max_slots=2, max_len=max_len,
+                           on_launch=captured.append)
+    reqs = [Request(uid=0, prompt=[5, 9, 2], max_new_tokens=5),
+            Request(uid=1, prompt=[3, 1], max_new_tokens=9)]
+    for r in reqs:
+        engine.submit(r)
+    engine.run_until_done()
+    rows = sum(int(d["live_mask"].sum()) for d in captured if "live_mask" in d)
+    assert rows == sum(len(r.generated) for r in reqs)
+    assert engine.ahead.launches > 0 and engine.ahead.dropped_rows == 0
+    # request 1 ends by its last row at max_len 10: prompt of 2, 8 tokens
+    want = [5, 9] if max_len == 64 else [5, max_len - 2]
+    assert [len(r.generated) for r in reqs] == want
+
+
+def test_run_until_done_leaves_no_launch_unread(small_model):
+    """Two requests that end on one EOS in the same launch leave a staged
+    launch whose rows are both dropped; ``run_until_done`` reads it."""
+    cfg, model, params = small_model
+    first = ServingEngine(model, params, max_slots=2, max_len=64)
+    for uid in range(2):
+        first.submit(Request(uid=uid, prompt=[3, 1, 4], max_new_tokens=16))
+    eos = first.run_until_done()[0].generated[4]
+    engine = ServingEngine(model, params, max_slots=2, max_len=64, eos_id=eos)
+    for uid in range(2):
+        engine.submit(Request(uid=uid, prompt=[3, 1, 4], max_new_tokens=16))
+    engine.step()
+    assert engine._unread is not None  # staged, read in the next step
+    done = engine.run_until_done()
+    assert [len(r.generated) for r in done] == [5, 5]
+    assert not engine.live_slots and engine._unread is None and engine.idle
+    assert engine.ahead.dropped_rows == 2
+    assert not engine.executor._inflight
+
+
+def _family_model(family):
+    cfg = dataclasses.replace(get(FAMILY_ARCH[family]).reduced(), remat="none")
+    if cfg.n_experts:
+        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
+    model = Model(cfg)
+    return model, model.init(jax.random.key(0))
+
+
+@pytest.mark.parametrize("family", FAMILY_ARCH)
+def test_eos_inside_a_staged_pair(family):
+    """A request ends on an EOS read while the next launch, staged ahead,
+    has decoded one more row for it: the row goes to no request and is
+    counted, the other slot's stream is unchanged, and the request admitted
+    into the freed slot before that launch is read decodes as in a fresh
+    engine (its recurrent state, where the family has one, zeroed)."""
+    model, params = _family_model(family)
+    first, _ = _staged_engine(model, params)
+    streams = {r.uid: r.generated for r in first.run_until_done()}
+    late = [8, 8, 6]
+    fresh = ServingEngine(model, params, max_slots=2, max_len=64, prefill_chunk=4)
+    fresh.submit(Request(uid=2, prompt=late, max_new_tokens=12))
+    (want,) = fresh.run_until_done()
+    a, b = streams[0], streams[1]
+    # a token first emitted mid-answer by request 0, and by no other
+    m = next(i for i in range(4, len(a))
+             if a[i] not in a[:i] + b + want.generated)
+    engine, (ra, rb) = _staged_engine(model, params, eos_id=a[m])
+    while not ra.done:
+        engine.step()
+    assert ra.generated == a[:m + 1]
+    assert engine._unread is not None and engine.ahead.dropped_rows == 0
+    rc = Request(uid=2, prompt=late, max_new_tokens=12)
+    engine.submit(rc)
+    engine.step()  # admits into the freed slot, then reads the staged launch
+    assert engine.slot_req[0] is rc and engine.ahead.dropped_rows == 1
+    engine.run_until_done()
+    assert ra.generated == a[:m + 1] and rb.generated == b
+    assert rc.generated == want.generated
+    assert engine.ahead.dropped_rows == 1

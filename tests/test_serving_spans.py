@@ -121,12 +121,17 @@ def test_span_names_and_nesting(traced):
             assert s.parent.start <= s.start and s.end <= s.parent.end
     steps = [s for s in spans if s.name == "serving.step"]
     assert [s.args["step_num"] for s in steps] == list(range(engine.steps))
-    # every step that decodes holds one launch, one sync and one retire
+    # every step that decodes reads one launch: one sync and one retire,
+    # after one decode launch or two (the second staged ahead of the read)
     for st in steps:
-        kids = [s.name for s in spans if s.parent is st]
+        kids = [s.name for s in sorted((s for s in spans if s.parent is st),
+                                       key=lambda s: s.start)
+                if s.name != "serving.admit"]
         if "serving.decode_launch" in kids:
-            assert sorted(k for k in kids if k != "serving.admit") == [
-                "serving.decode_launch", "serving.retire", "serving.sync"]
+            n = kids.count("serving.decode_launch")
+            assert 1 <= n <= 2
+            assert kids == ["serving.decode_launch"] * n + [
+                "serving.sync", "serving.retire"]
             sync = next(s for s in spans if s.parent is st and s.name == "serving.sync")
             assert sync.args["d2h_bytes"] == SLOTS * 4
 
@@ -178,6 +183,32 @@ def test_decode_args_and_produced(traced):
     for d in (s for s in spans if s.name == "serving.decode_launch"):
         assert d.parent.args["produced"] == d.args["live"]
         assert d.args["live"] <= d.args["context_tokens"]
+
+
+def test_decode_launch_says_whether_it_was_staged_ahead(traced):
+    """``ahead`` marks a launch dispatched before the read of the launch
+    before it, which happens only with every slot live. Such a launch is
+    read in the next step, which launches no decode of its own: at most the
+    next one, staged ahead in turn."""
+    engine, _, _, spans = traced
+    steps = sorted((s for s in spans if s.name == "serving.step"),
+                   key=lambda s: s.start)
+    launches = {id(st): [d for d in sorted(spans, key=lambda s: s.start)
+                         if d.parent is st and d.name == "serving.decode_launch"]
+                for st in steps}
+    ahead = [d for ds in launches.values() for d in ds if d.args["ahead"]]
+    assert len(ahead) == engine.ahead.launches >= 1
+    assert all(d.args["live"] == SLOTS for d in ahead)  # only with every slot live
+    assert engine.ahead.dropped_rows == 0  # no EOS here
+    for prev, st in zip(steps, steps[1:]):
+        mine, before = launches[id(st)], launches[id(prev)]
+        staged = bool(before) and before[-1].args["ahead"]
+        # a step after a staged launch launches nothing of its own: only
+        # the next launch staged ahead, or none
+        if staged:
+            assert len(mine) <= 1 and all(d.args["ahead"] for d in mine)
+        else:
+            assert mine == [] or not mine[0].args["ahead"]
 
 
 def test_no_argument_is_computed_with_the_profiler_off(small_model, monkeypatch,
